@@ -61,6 +61,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    from repro.launch.compile_cache import use_compilation_cache
+
+    use_compilation_cache()
 
     benches = all_benchmarks()
     names = args.only.split(",") if args.only else list(benches)
@@ -68,13 +71,15 @@ def main() -> None:
     if args.json:
         os.makedirs(args.json, exist_ok=True)
     rows = []
+    failed = []
     print("name,value,derived")
     for name in names:
         t0 = time.time()
         try:
             out = benches[name]()
-        except Exception as e:  # noqa: BLE001 — keep the suite going
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
             print(f"{name}/ERROR,{type(e).__name__},{e}", flush=True)
+            failed.append(name)
             continue
         finally:
             # the suite compiles hundreds of distinct programs; without this
@@ -98,6 +103,8 @@ def main() -> None:
         w = csv.DictWriter(f, fieldnames=["name", "value", "derived"])
         w.writeheader()
         w.writerows(rows)
+    if failed:
+        sys.exit(f"benchmark targets failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -662,9 +662,9 @@ out = {"_partitioning": {
     "outer_tp": parts.outer_tp,
 }}
 for name, (pallas_fn, xla_fn) in cases.items():
-    with kernel_partitioning(parts), mesh:
+    with kernel_partitioning(parts), jax.set_mesh(mesh):
         t_sm = timeit(jax.jit(pallas_fn))
-    with mesh:
+    with jax.set_mesh(mesh):
         t_xla = timeit(jax.jit(xla_fn))
     out[name] = {"shard_map_us": t_sm, "xla_us": t_xla}
 print(json.dumps(out))
@@ -690,7 +690,15 @@ def bench_mesh_kernels() -> list[dict]:
     import subprocess
     import sys
 
-    env = dict(os.environ, PYTHONPATH="src")
+    if jax.default_backend() != "cpu":
+        # this process already holds the accelerator, so a child that needs
+        # the devices would fail or hang; the multi-chip path is checked by
+        # `python chip_smoke.py --four-chips` in one process instead
+        raise RuntimeError(
+            f"mesh_kernel_bench runs its 8-device child on virtual CPU "
+            f"devices only (backend here: {jax.default_backend()}); on the "
+            f"chip run `python chip_smoke.py --four-chips`")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     res = subprocess.run([sys.executable, "-c", _MESH_KERNEL_CHILD],
                          capture_output=True, text=True, env=env, timeout=900)
     if res.returncode != 0:

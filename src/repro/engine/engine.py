@@ -269,7 +269,7 @@ class TrainEngine:
         if self.mesh is not None:
             from repro.launch.sharding import batch_shardings
 
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 if eval_batches is not None:
                     eval_batches = jax.device_put(
                         eval_batches, batch_shardings(

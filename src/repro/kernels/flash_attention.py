@@ -7,10 +7,14 @@ lowered TPU-style a la the maxtext block kernels), following the same
 pattern the repo already uses for Newton-Schulz (``kernels/matmul.py``) and
 quantization (``kernels/quantize.py``):
 
-* **GQA-native layout**: queries travel as ``[B*KV, S, G, hd]`` (G = H/KV
+* **GQA-native layout**: queries travel as ``[B*KV, G, S, hd]`` (G = H/KV
   query heads per KV head), K/V as ``[B*KV, S, hd]`` — each K/V tile is
   loaded into VMEM once per q block and shared by all G query heads, never
-  materialized H/KV times.
+  materialized H/KV times. G leads the q tile, so the kernel merges
+  ``[G, bq, hd] -> [G*bq, hd]`` along the sublane axis into one MXU operand
+  (Mosaic refuses the ``[bq, G, hd]`` merge in bf16). Per-row softmax
+  statistics (``lse``, ``dl``) travel as ``[B*KV, G, S, 1]`` for the same
+  reason.
 * **Online softmax**: fp32 ``m``/``l``/``acc`` accumulators live in VMEM
   scratch across the kv-block sweep; the epilogue normalizes once and also
   emits the per-row logsumexp for the backward pass.
@@ -27,9 +31,10 @@ quantization (``kernels/quantize.py``):
   XLA blockwise fallback. Two kernels: a q-major sweep for dq and a
   kv-major sweep for dk/dv, both on the same skip schedule.
 
-Like the other kernels, this runs ``interpret=True`` off-TPU (the CPU test
-target). On multi-device meshes the call sites consult the kernel
-partitioning context (:mod:`repro.kernels.partition`): when the StepPlan
+Like the other kernels, this runs in interpret mode off-TPU (the CPU test
+target; :func:`repro.kernels.backend.pallas_interpret` decides). On
+multi-device meshes the call sites consult the kernel partitioning context
+(:mod:`repro.kernels.partition`): when the StepPlan
 machinery routes a mesh, the custom-VJP call — forward and both backward
 sweeps — is wrapped in ``shard_map`` over the fused [B*KV, ...] batch-head
 axis (:func:`flash_specs`), so ``attn_impl='pallas'`` lowers under GSPMD
@@ -50,6 +55,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.backend import pallas_interpret
 from repro.kernels.partition import (
     KernelPartitioning,
     active_partitioning,
@@ -60,10 +66,10 @@ from repro.kernels.partition import (
 NEG_INF = -2.0e38
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 1024
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# The backward sweeps hold several [G*bq, bkv] f32 tiles (scores, probs, dp,
+# ds): 17 MiB at G=3 and the default blocks, past Mosaic's 16 MiB default
+# scoped-VMEM limit. A v5e core has 128 MiB of VMEM.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
 
 # ---------------------------------------------------------------------------
@@ -158,15 +164,17 @@ def _sched_array(nq: int, nkv: int, block_q: int, block_kv: int,
 
 
 # ---------------------------------------------------------------------------
-# Kernels (q [BKV, S, G, hd]; k/v [BKV, S, hd]; fp32 accumulation in VMEM)
+# Kernels (q [BKV, G, S, hd]; k/v [BKV, S, hd]; fp32 accumulation in VMEM)
 # ---------------------------------------------------------------------------
 
 
 def _mask_and_positions(qi, kj, bq, bkv, G, causal, window):
-    """Unmasked-entry predicate for the [bq*G, bkv] score tile."""
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq * G, bkv), 0) // G
-    cols = kj * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq * G, bkv), 1)
-    mask = jnp.ones((bq * G, bkv), bool)
+    """Unmasked-entry predicate for the [G*bq, bkv] score tile (row
+    ``g*bq + r`` is query row r of head g)."""
+    rows = qi * bq + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (G * bq, bkv), 0), bq)
+    cols = kj * bkv + jax.lax.broadcasted_iota(jnp.int32, (G * bq, bkv), 1)
+    mask = jnp.ones((G * bq, bkv), bool)
     if causal:
         mask &= rows >= cols
     if window:
@@ -175,7 +183,7 @@ def _mask_and_positions(qi, kj, bq, bkv, G, causal, window):
 
 
 def _scores(q_ref, k_ref, bq, G, hd, scale):
-    q = q_ref[0].reshape(bq * G, hd).astype(jnp.float32)
+    q = q_ref[0].reshape(G * bq, hd).astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32) * scale
@@ -213,17 +221,17 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(sched_ref[g, 3] == 1)
     def _epilogue():
         l = jnp.maximum(l_new, 1e-30)
-        o_ref[0] = (acc_new / l).reshape(bq, G, hd).astype(o_ref.dtype)
-        lse_ref[0] = (m_new + jnp.log(l)).reshape(bq, G)
+        o_ref[0] = (acc_new / l).reshape(G, bq, hd).astype(o_ref.dtype)
+        lse_ref[0] = (m_new + jnp.log(l)).reshape(G, bq, 1)
 
 
 def _probs(sched_ref, q_ref, k_ref, lse_ref, g, *, bq, bkv, G, hd,
            causal, window, scale):
-    """Recompute the [bq*G, bkv] probability tile from the saved logsumexp."""
+    """Recompute the [G*bq, bkv] probability tile from the saved logsumexp."""
     qi, kj = sched_ref[g, 0], sched_ref[g, 1]
     s = _scores(q_ref, k_ref, bq, G, hd, scale)
     mask = _mask_and_positions(qi, kj, bq, bkv, G, causal, window)
-    lse = lse_ref[0].reshape(bq * G, 1)
+    lse = lse_ref[0].reshape(G * bq, 1)
     return jnp.where(mask, jnp.exp(s - lse), 0.0)
 
 
@@ -237,18 +245,18 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     p = _probs(sched_ref, q_ref, k_ref, lse_ref, g, bq=bq, bkv=bkv, G=G,
                hd=hd, causal=causal, window=window, scale=scale)
-    do = do_ref[0].reshape(bq * G, hd).astype(jnp.float32)
+    do = do_ref[0].reshape(G * bq, hd).astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dl_ref[0].reshape(bq * G, 1))
+    ds = p * (dp - dl_ref[0].reshape(G * bq, 1))
     k = k_ref[0].astype(jnp.float32)
     dq_scr[...] += scale * jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(sched_ref[g, 3] == 1)
     def _epilogue():
-        dq_ref[0] = dq_scr[...].reshape(bq, G, hd).astype(dq_ref.dtype)
+        dq_ref[0] = dq_scr[...].reshape(G, bq, hd).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -263,14 +271,14 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     p = _probs(sched_ref, q_ref, k_ref, lse_ref, g, bq=bq, bkv=bkv, G=G,
                hd=hd, causal=causal, window=window, scale=scale)
-    do = do_ref[0].reshape(bq * G, hd).astype(jnp.float32)
+    do = do_ref[0].reshape(G * bq, hd).astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - dl_ref[0].reshape(bq * G, 1))
-    q = q_ref[0].reshape(bq * G, hd).astype(jnp.float32)
+    ds = p * (dp - dl_ref[0].reshape(G * bq, 1))
+    q = q_ref[0].reshape(G * bq, hd).astype(jnp.float32)
     dk_scr[...] += scale * jax.lax.dot_general(
         ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
@@ -289,7 +297,7 @@ def _grid_spec(sched: np.ndarray, BKV: int, bq: int, bkv: int, G: int,
                hd: int, extra_in: list, extra_out: list, scratch: list):
     """PrefetchScalarGridSpec shared by all three sweeps: the schedule rides
     as scalar prefetch and the index maps read (qi, kj) off it."""
-    q_spec = pl.BlockSpec((1, bq, G, hd), lambda b, g, s: (b, s[g, 0], 0, 0))
+    q_spec = pl.BlockSpec((1, G, bq, hd), lambda b, g, s: (b, 0, s[g, 0], 0))
     kv_spec = pl.BlockSpec((1, bkv, hd), lambda b, g, s: (b, s[g, 1], 0))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -301,36 +309,38 @@ def _grid_spec(sched: np.ndarray, BKV: int, bq: int, bkv: int, G: int,
 
 
 def _fwd(q, k, v, *, causal, window, bq, bkv, scale, interpret, skip):
-    BKV, S, G, hd = q.shape
+    BKV, G, S, hd = q.shape
     nq, nkv = S // bq, S // bkv
     sched = _sched_array(nq, nkv, bq, bkv, causal, window, False, skip)
     kernel = functools.partial(_fwd_kernel, bq=bq, bkv=bkv, G=G, hd=hd,
                                causal=causal, window=window, scale=scale)
-    q_out = pl.BlockSpec((1, bq, G, hd), lambda b, g, s: (b, s[g, 0], 0, 0))
-    lse_out = pl.BlockSpec((1, bq, G), lambda b, g, s: (b, s[g, 0], 0))
+    q_out = pl.BlockSpec((1, G, bq, hd), lambda b, g, s: (b, 0, s[g, 0], 0))
+    lse_out = pl.BlockSpec((1, G, bq, 1), lambda b, g, s: (b, 0, s[g, 0], 0))
     grid_spec = _grid_spec(
         sched, BKV, bq, bkv, G, hd, extra_in=[],
         extra_out=[q_out, lse_out],
-        scratch=[pltpu.VMEM((bq * G, 1), jnp.float32),
-                 pltpu.VMEM((bq * G, 1), jnp.float32),
-                 pltpu.VMEM((bq * G, hd), jnp.float32)])
+        scratch=[pltpu.VMEM((G * bq, 1), jnp.float32),
+                 pltpu.VMEM((G * bq, 1), jnp.float32),
+                 pltpu.VMEM((G * bq, hd), jnp.float32)])
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BKV, S, G, hd), q.dtype),
-                   jax.ShapeDtypeStruct((BKV, S, G), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((BKV, G, S, hd), q.dtype),
+                   jax.ShapeDtypeStruct((BKV, G, S, 1), jnp.float32)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(jnp.asarray(sched), q, k, v)
 
 
 def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bkv, scale, interpret,
          skip):
-    BKV, S, G, hd = q.shape
+    BKV, G, S, hd = q.shape
     nq, nkv = S // bq, S // bkv
     # dl = rowsum(do * o): the only extra residual the flash backward needs
-    dl = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    do_spec = pl.BlockSpec((1, bq, G, hd), lambda b, g, s: (b, s[g, 0], 0, 0))
-    row_spec = pl.BlockSpec((1, bq, G), lambda b, g, s: (b, s[g, 0], 0))
+    dl = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    do_spec = pl.BlockSpec((1, G, bq, hd), lambda b, g, s: (b, 0, s[g, 0], 0))
+    row_spec = pl.BlockSpec((1, G, bq, 1), lambda b, g, s: (b, 0, s[g, 0], 0))
     kv_out = pl.BlockSpec((1, bkv, hd), lambda b, g, s: (b, s[g, 1], 0))
     kw = dict(bq=bq, bkv=bkv, G=G, hd=hd, causal=causal, window=window,
               scale=scale)
@@ -342,9 +352,10 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bkv, scale, interpret,
             sched_q, BKV, bq, bkv, G, hd,
             extra_in=[do_spec, row_spec, row_spec],
             extra_out=[do_spec],
-            scratch=[pltpu.VMEM((bq * G, hd), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((BKV, S, G, hd), q.dtype)],
+            scratch=[pltpu.VMEM((G * bq, hd), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((BKV, G, S, hd), q.dtype)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(jnp.asarray(sched_q), q, k, v, do, lse, dl)[0]
 
     sched_kv = _sched_array(nq, nkv, bq, bkv, causal, window, True, skip)
@@ -359,6 +370,7 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bkv, scale, interpret,
         out_shape=[jax.ShapeDtypeStruct((BKV, S, hd), k.dtype),
                    jax.ShapeDtypeStruct((BKV, S, hd), v.dtype)],
         interpret=interpret,
+        compiler_params=_COMPILER_PARAMS,
     )(jnp.asarray(sched_kv), q, k, v, do, lse, dl)
     return dq, dk, dv
 
@@ -366,7 +378,7 @@ def _bwd(q, k, v, o, lse, do, *, causal, window, bq, bkv, scale, interpret,
 @functools.lru_cache(maxsize=None)
 def _flash_fn(causal: bool, window: int, bq: int, bkv: int, scale: float,
               interpret: bool, skip: bool):
-    """custom_vjp'd [BKV, S, G, hd] attention for one static config."""
+    """custom_vjp'd [BKV, G, S, hd] attention for one static config."""
 
     @jax.custom_vjp
     def fn(q, k, v):
@@ -395,7 +407,7 @@ def _flash_fn(causal: bool, window: int, bq: int, bkv: int, scale: float,
 
 
 def flash_specs(part: KernelPartitioning, lead: int) -> tuple[P, P]:
-    """(q_spec [lead, S, G, hd], kv_spec [lead, S, hd]) for the fused
+    """(q_spec [lead, G, S, hd], kv_spec [lead, S, hd]) for the fused
     batch-head axis. ``lead = B*KV`` is B-major, so the ('data', 'model')
     preference aligns batch with 'data' and kv-heads with 'model'; S stays
     whole per device (the visit schedule is global over S). The specs serve
@@ -529,8 +541,7 @@ def _paged_decode_xla(q, k_pages, v_pages, page_table, lengths, *, window):
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            page_table: jax.Array, lengths: jax.Array, *,
-                           window: int = 0, impl: str = "xla",
-                           interpret: bool | None = None) -> jax.Array:
+                           window: int = 0, impl: str = "xla") -> jax.Array:
     """One-token GQA attention against a paged KV cache.
 
     q ``[B, H, hd]`` (the new token per sequence slot, RoPE applied);
@@ -550,10 +561,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     G = H // KV
     qg = q.reshape(B, KV, G, hd)
     if impl == "pallas":
-        if interpret is None:
-            interpret = _interpret()
         local = functools.partial(_paged_decode_pallas, window=window,
-                                  interpret=interpret)
+                                  interpret=pallas_interpret())
         part = active_partitioning()
         if part is not None:
             q_spec, tbl_spec, len_spec, pool_spec = paged_specs(part, B)
@@ -577,7 +586,6 @@ def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int = 0,
                         block_q: int = DEFAULT_BLOCK_Q,
                         block_kv: int = DEFAULT_BLOCK_KV,
-                        interpret: bool | None = None,
                         skip_blocks: bool = True) -> jax.Array:
     """Fused GQA flash attention.
 
@@ -594,14 +602,12 @@ def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     G = H // KV
     bq = clamp_block(block_q, S)
     bkv = clamp_block(block_kv, S)
-    if interpret is None:
-        interpret = _interpret()
     scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(B, S, KV, G, hd).transpose(0, 2, 1, 3, 4).reshape(B * KV, S, G, hd)
+    qg = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4).reshape(B * KV, G, S, hd)
     kg = k.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
     vg = v.transpose(0, 2, 1, 3).reshape(B * KV, S, hd)
-    fn = _flash_fn(bool(causal), int(window), bq, bkv, scale, bool(interpret),
-                   bool(skip_blocks))
+    fn = _flash_fn(bool(causal), int(window), bq, bkv, scale,
+                   pallas_interpret(), bool(skip_blocks))
     part = active_partitioning()
     if part is not None:
         # shard_map OUTSIDE the custom_vjp: jax differentiates through the
@@ -611,4 +617,4 @@ def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         fn = shard_wrap(fn, part, in_specs=(q_spec, kv_spec, kv_spec),
                         out_specs=q_spec)
     o = fn(qg, kg, vg)
-    return o.reshape(B, KV, S, G, hd).transpose(0, 2, 1, 3, 4).reshape(B, S, H, hd)
+    return o.reshape(B, KV, G, S, hd).transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

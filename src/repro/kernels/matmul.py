@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x ships TPUCompilerParams; newer releases renamed it CompilerParams
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def ns_stack_spec(part, bsz: int):
     """shard_map spec for a [bsz, m, n] Newton-Schulz matrix stack.
@@ -73,7 +70,7 @@ def matmul_epilogue(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
     out_dtype=None,
 ) -> jax.Array:
     """C = alpha * (a @ b) + beta * d for 2-D operands (pre-padded shapes)."""
@@ -105,7 +102,7 @@ def matmul_epilogue(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a, b, d)

@@ -1,5 +1,6 @@
 """Public wrappers around the Pallas kernels: padding to block multiples,
-batching, backend selection (interpret=True off-TPU), and mesh routing.
+batching, backend selection (:func:`repro.kernels.backend.pallas_interpret`),
+and mesh routing.
 
 Each wrapper consults the kernel-partitioning context
 (:mod:`repro.kernels.partition`) *outside* any jit cache: with no mesh
@@ -25,16 +26,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune
+from repro.kernels.backend import pallas_interpret
 from repro.kernels.matmul import matmul_epilogue, ns_stack_spec
 from repro.kernels.outer_update import fused_nesterov_update, outer_update_spec
 from repro.kernels.partition import active_partitioning, shard_wrap
-from repro.kernels.quantize import rowwise_dequantize, rowwise_quantize, rowwise_specs
+from repro.kernels.quantize import (
+    DEFAULT_BLOCK_ROWS,
+    rowwise_dequantize,
+    rowwise_quantize,
+    rowwise_specs,
+)
 from repro.kernels.topk_pack import pack_topk, unpack_topk  # noqa: F401 (re-export)
 from repro.optim.muon import NS_COEFFS
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
@@ -61,7 +64,7 @@ def matmul(a: jax.Array, b: jax.Array, d: jax.Array | None = None, *,
     dp = _pad_to(d, (block, block)) if d is not None else None
     out = matmul_epilogue(ap, bp, dp, alpha=alpha, beta=beta,
                           block_m=block, block_n=block, block_k=block,
-                          interpret=_interpret())
+                          interpret=pallas_interpret())
     return out[:m, :n]
 
 
@@ -127,11 +130,8 @@ def ns_orthogonalize(g: jax.Array, iters: int = 5, eps: float = 1e-7,
 
 
 def _quantize_body(x: jax.Array, *, bits: int, block_rows: int):
-    m, _ = x.shape
-    xp = _pad_to(x, (block_rows, 1))
-    deq, codes, lo, scale = rowwise_quantize(xp, bits, block_rows=block_rows,
-                                             interpret=_interpret())
-    return deq[:m], codes[:m], lo[:m], scale[:m]
+    return rowwise_quantize(x, bits, block_rows=block_rows,
+                            interpret=pallas_interpret())
 
 
 @partial(jax.jit, static_argnames=("bits", "block_rows"))
@@ -146,12 +146,13 @@ def quantize_rowwise(x: jax.Array, bits: int = 4, block_rows: int | None = None)
     (rows are independent — each carries its own lo/scale).
 
     ``block_rows=None`` consults the autotune table for this wire shape and
-    falls back to the historical 8 on a miss. block_rows is pure row tiling
+    falls back to ``DEFAULT_BLOCK_ROWS`` on a miss (every TPU lookup misses:
+    the committed entries are keyed ``/cpu``). block_rows is pure row tiling
     (every row quantizes against its own lo/scale), so any tuned value is
     bitwise-inert — the sweep's gate re-verifies that per shape anyway."""
     if block_rows is None:
         block_rows = autotune.quantize_block_rows(
-            x.shape[0], x.shape[1], bits, str(x.dtype)) or 8
+            x.shape[0], x.shape[1], bits, str(x.dtype)) or DEFAULT_BLOCK_ROWS
     part = active_partitioning()
     if part is None:
         return _quantize_rowwise_jit(x, bits, block_rows)
@@ -163,13 +164,8 @@ def quantize_rowwise(x: jax.Array, bits: int = 4, block_rows: int | None = None)
 
 def _dequantize_body(codes: jax.Array, lo: jax.Array, scale: jax.Array, *,
                      block_rows: int) -> jax.Array:
-    m, _ = codes.shape
-    cp = _pad_to(codes, (block_rows, 1))
-    lp = _pad_to(lo, (block_rows, 1))
-    sp = _pad_to(scale, (block_rows, 1))
-    out = rowwise_dequantize(cp, lp, sp, block_rows=block_rows,
-                             interpret=_interpret())
-    return out[:m]
+    return rowwise_dequantize(codes, lo, scale, block_rows=block_rows,
+                              interpret=pallas_interpret())
 
 
 @partial(jax.jit, static_argnames=("block_rows",))
@@ -186,7 +182,7 @@ def dequantize_rowwise(codes: jax.Array, lo: jax.Array, scale: jax.Array,
     ends of the wire pick the same tiling."""
     if block_rows is None:
         block_rows = autotune.quantize_block_rows(
-            codes.shape[0], codes.shape[1], 4, "float32") or 8
+            codes.shape[0], codes.shape[1], 4, "float32") or DEFAULT_BLOCK_ROWS
     part = active_partitioning()
     if part is None:
         return _dequantize_rowwise_jit(codes, lo, scale, block_rows)
@@ -201,7 +197,7 @@ def _nesterov_flat(t: jax.Array, p: jax.Array, uu: jax.Array, *,
     n = t.shape[0]
     t2, u2 = fused_nesterov_update(
         _pad_to(t, (block,)), _pad_to(p, (block,)), _pad_to(uu, (block,)),
-        lr=lr, momentum=momentum, block=block, interpret=_interpret())
+        lr=lr, momentum=momentum, block=block, interpret=pallas_interpret())
     return t2[:n], u2[:n]
 
 

@@ -71,7 +71,7 @@ def fused_nesterov_update(
     lr: float,
     momentum: float,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Flat [n] arrays (n % block == 0; ops.py pads) -> (theta', u')."""
     (n,) = theta.shape

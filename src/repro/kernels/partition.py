@@ -4,7 +4,7 @@ Pallas calls carry no GSPMD partitioning rules, so a bare ``pl.pallas_call``
 inside a jit that spans a multi-device mesh fails to lower — which is why
 every fused kernel used to fall back to XLA on the production mesh. The fix
 is the maxtext-DiLoCo combination: wrap the kernel call in
-``jax.experimental.shard_map`` with explicit PartitionSpecs, so GSPMD sees
+``jax.shard_map`` with explicit PartitionSpecs, so GSPMD sees
 an opaque per-device region and each device runs the kernel on its local
 block. All five kernels are embarrassingly parallel over the axes we shard
 (batch*kv-head rows for flash attention, quantize rows, stacked
@@ -38,7 +38,7 @@ import dataclasses
 from contextvars import ContextVar
 from typing import Any, Callable
 
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import Mesh
 
 
@@ -135,9 +135,9 @@ def shard_wrap(fn: Callable, part: KernelPartitioning,
                in_specs: Any, out_specs: Any) -> Callable:
     """shard_map ``fn`` on the routed mesh.
 
-    ``check_rep=False``: the kernel bodies are opaque to shard_map's
-    replication checker (pallas_call has no replication rule), and every
+    ``check_vma=False``: the kernel bodies are opaque to shard_map's
+    varying-axes checker (pallas_call has no rule for it), and every
     wrapped kernel is batch-local — no cross-device reduction ever happens
     inside the mapped region."""
-    return shard_map(fn, mesh=part.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=part.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

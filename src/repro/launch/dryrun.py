@@ -127,7 +127,7 @@ def run_one(arch: str, shape: str, multi_pod: bool, sync_interval: int = 30,
         }
         t0 = time.time()
         try:
-            with mesh:
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(
                     plan.fn,
                     in_shardings=plan.in_shardings,

@@ -13,20 +13,28 @@ state — the dry-run must set XLA_FLAGS before any device query.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings, and
+    the bare-PartitionSpec ``with_sharding_constraint`` hints of
+    :func:`repro.models.common.shard_hint` are legal (``jax.make_mesh``
+    defaults to ``Explicit`` axes, which reject them)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1, pod: int = 0) -> Mesh:
     """Small mesh over however many (host) devices exist — for tests."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict[str, int]:

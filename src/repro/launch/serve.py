@@ -22,6 +22,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import get_config, reduce_config
 from repro.models import build_model
@@ -70,9 +71,11 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main() -> None:
-    args = build_parser().parse_args()
+def serve(args) -> dict:
+    """Serve ``args.batch`` seeded random prompts to completion.
 
+    Returns ``{"tokens": {rid: np.ndarray[max_new]}, "n_new": int}``; the
+    dense-cache ``naive`` engine reports its batch under rids ``req0..``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_config(cfg)
@@ -97,17 +100,24 @@ def main() -> None:
         reqs = [Request(f"req{i}", tuple(int(t) for t in row), args.max_new)
                 for i, row in enumerate(jax.device_get(prompts))]
         results = engine.run(reqs)
-        dt = time.time() - t0
-        sample = results["req0"][:8].tolist()
     else:
         toks = generate(model, params, prompts, args.max_new,
                         temperature=args.temperature, context=ctx, rng=rng)
-        dt = time.time() - t0
-        sample = toks[0, args.prompt_len: args.prompt_len + 8].tolist()
+        new = np.asarray(toks[:, args.prompt_len:])
+        results = {f"req{i}": row for i, row in enumerate(new)}
+    dt = time.time() - t0
     n_new = args.batch * args.max_new
     print(f"[{args.engine}] generated {n_new} tokens in {dt:.2f}s "
           f"({n_new/dt:.1f} tok/s)")
-    print("sample:", sample)
+    print("sample:", results["req0"][:8].tolist())
+    return {"tokens": results, "n_new": n_new}
+
+
+def main() -> None:
+    from repro.launch.compile_cache import use_compilation_cache
+
+    use_compilation_cache()
+    serve(build_parser().parse_args())
 
 
 if __name__ == "__main__":

@@ -377,7 +377,7 @@ def train(args) -> dict:
     print(f"final smoothed eval loss: {final:.4f} "
           f"(floor={data.entropy_floor_nats():.4f} nats)")
     return {"final_loss": final, "losses": losses, "steps": steps, "state": state,
-            "telemetry": telemetry}
+            "telemetry": telemetry, "history": _history}
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -523,4 +523,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compilation_cache
+
+    use_compilation_cache()
     train(build_parser().parse_args())

@@ -168,7 +168,7 @@ class PagedEngine:
     def _mesh_ctx(self):
         from contextlib import nullcontext
 
-        return self.mesh if self.mesh is not None else nullcontext()
+        return jax.set_mesh(self.mesh) if self.mesh is not None else nullcontext()
 
     def _init_state(self) -> DecodeState:
         return DecodeState(

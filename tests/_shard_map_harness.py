@@ -59,7 +59,7 @@ def close(a, b, tol=2e-5) -> bool:
 
 def sharded(fn, *args):
     """Run ``jit(fn)`` with the kernel routing installed on the mesh."""
-    with kernel_partitioning(PARTS), MESH:
+    with kernel_partitioning(PARTS), jax.set_mesh(MESH):
         return jax.tree.map(lambda x: np.asarray(x), jax.jit(fn)(*args))
 
 
@@ -108,7 +108,7 @@ def main() -> dict:
         return (jax.vmap(g, spmd_axis_name=spmd) if spmd else jax.vmap(g))
 
     gref = single(grads(None), qk, kk, vk)
-    with kernel_partitioning(PARTS), MESH:
+    with kernel_partitioning(PARTS), jax.set_mesh(MESH):
         shard = NamedSharding(MESH, P("pod"))
         args = [jax.device_put(x, shard) for x in (qk, kk, vk)]
         gout = jax.tree.map(lambda x: np.asarray(x),

@@ -30,12 +30,14 @@ from repro.launch.serve import build_parser as serve_parser
 from benchmarks.run import build_parser as bench_parser
 from benchmarks.check_regression import build_parser as regression_parser
 from repro.kernels.autotune import build_parser as autotune_parser
+from chip_smoke import build_parser as chip_smoke_parser
 
 out = {}
 for name, build in [("train", train_parser), ("dryrun", dryrun_parser),
                     ("serve", serve_parser), ("benchmarks", bench_parser),
                     ("check_regression", regression_parser),
-                    ("autotune", autotune_parser)]:
+                    ("autotune", autotune_parser),
+                    ("chip_smoke", chip_smoke_parser)]:
     flags = set()
     for action in build()._actions:
         flags.update(o for o in action.option_strings if o.startswith("--"))

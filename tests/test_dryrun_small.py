@@ -36,7 +36,7 @@ for arch, shape in [("smollm-135m", "train_4k"), ("mamba2-370m", "decode_32k"),
     plans = build_plans(cfg, shape, mesh, **(
         {"dcfg": DiLoCoConfig(n_workers=2, sync_interval=4)} if shape == "train_4k" else {}))
     for plan in plans:
-        with mesh:
+        with jax.set_mesh(mesh):
             c = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                         donate_argnums=plan.donate).lower(*plan.args).compile()
         coll = collective_bytes_corrected(c.as_text())
@@ -60,7 +60,7 @@ cfg = reduce_config(get_config("smollm-135m"))
 plans = build_plans(cfg, "train_4k", nopod,
                    dcfg=DiLoCoConfig(n_workers=1, sync_interval=4))
 for plan in plans:
-    with nopod:
+    with jax.set_mesh(nopod):
         c = jax.jit(plan.fn, in_shardings=plan.in_shardings,
                     donate_argnums=plan.donate).lower(*plan.args).compile()
     rec = {"ok": True}

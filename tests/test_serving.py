@@ -183,7 +183,7 @@ def test_paged_decode_attention_matches_oracle(window):
     xla = paged_decode_attention(q, k_pages, v_pages, table, lengths,
                                  window=window, impl="xla")
     pal = paged_decode_attention(q, k_pages, v_pages, table, lengths,
-                                 window=window, impl="pallas", interpret=True)
+                                 window=window, impl="pallas")
     assert np.all(np.isfinite(np.asarray(ref)))
     np.testing.assert_allclose(np.asarray(xla), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
