@@ -214,14 +214,11 @@ def run_train(name: str, argv: list[str]) -> dict:
 
     args = build_parser().parse_args(argv)
     with CompileClock() as clock:
-        t0 = time.perf_counter()
         res = train(args)
-        wall = time.perf_counter() - t0
     out = {
         "phase": name,
         "compile_s": clock.seconds,
         "compile_cache": clock.cache,
-        "s_per_round": (wall - clock.seconds) / args.rounds,
         "train_loss": [h["train_loss"] for h in res["history"]],
         "eval_loss": list(res["losses"]),
         "_state": res["state"],

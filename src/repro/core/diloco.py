@@ -51,6 +51,7 @@ from repro.optim import (
     make_inner_optimizer,
     make_outer_transform,
 )
+from repro.tracing import FWD_BWD, INNER_OPT, OUTER_SYNC, OUTER_UPDATE, PSEUDOGRAD, REDUCE
 
 PyTree = Any
 
@@ -162,6 +163,7 @@ class OuterOptimizer:
 
     # -- the sync ------------------------------------------------------------
 
+    @jax.named_scope(REDUCE)
     def reduce(self, params: PyTree, deltas: PyTree, ef: PyTree | None,
                mask: PyTree | None = None,
                participation: jax.Array | None = None):
@@ -204,6 +206,7 @@ class OuterOptimizer:
                 new_ef, ef)
         return psi, new_ef
 
+    @jax.named_scope(OUTER_UPDATE)
     def descend(self, params: PyTree, psi: PyTree, opt_state: PyTree):
         """The terminal half: outer transform update + parameter descent on
         an already-reduced pseudogradient. Returns ``(new_params, new_opt)``.
@@ -313,8 +316,10 @@ def inner_step(model: Model, opt, state: PyTree, batch: PyTree,
     elementwise, so it is bitwise-equal to the maskless program."""
 
     def one(params_k, inner_k, batch_k):
-        (loss, metrics), grads = jax.value_and_grad(model.loss, has_aux=True)(params_k, batch_k)
-        new_p, new_s = opt.step(params_k, grads, inner_k)
+        with jax.named_scope(FWD_BWD):
+            (loss, metrics), grads = jax.value_and_grad(model.loss, has_aux=True)(params_k, batch_k)
+        with jax.named_scope(INNER_OPT):
+            new_p, new_s = opt.step(params_k, grads, inner_k)
         return new_p, new_s, loss
 
     new_wp, new_is, losses = jax.vmap(one, spmd_axis_name=spmd_axis)(
@@ -341,6 +346,7 @@ def inner_step(model: Model, opt, state: PyTree, batch: PyTree,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope(PSEUDOGRAD)
 def compute_deltas(state: PyTree) -> PyTree:
     """Δ_k = θ_outer − θ_k, stacked [K, ...] (paper Alg. 1 line 9)."""
     return jax.tree.map(
@@ -447,6 +453,7 @@ def outer_step(dcfg: DiLoCoConfig, state: PyTree, mask: PyTree | None = None,
             mask=mask, participation=participation)
 
     # broadcast synced params back to workers (masked portions only)
+    @jax.named_scope(OUTER_UPDATE)
     def reset(o, w, m=None):
         ob = jnp.broadcast_to(o[None].astype(w.dtype), w.shape)
         if m is None:
@@ -560,8 +567,9 @@ def diloco_round(model: Model, dcfg: DiLoCoConfig, opt, state: PyTree, batches: 
 
         def run_round(state, part):
             state, losses = scan_inner(state, batches, part)
-            state, psi = outer_step(dcfg, state, outer=outer,
-                                    participation=part)
+            with jax.named_scope(OUTER_SYNC):
+                state, psi = outer_step(dcfg, state, outer=outer,
+                                        participation=part)
             return state, losses, psi
 
         def finish(state, losses, psi):
@@ -611,8 +619,9 @@ def diloco_round(model: Model, dcfg: DiLoCoConfig, opt, state: PyTree, batches: 
         for j in range(J):
             seg_batches = jax.tree.map(lambda b: b[j * seg : (j + 1) * seg], batches)
             state, losses = scan_inner(state, seg_batches, part)
-            state, psi_j = outer_step(dcfg, state, mask=masks[j], outer=outer,
-                                      participation=part)
+            with jax.named_scope(OUTER_SYNC):
+                state, psi_j = outer_step(dcfg, state, mask=masks[j], outer=outer,
+                                          participation=part)
             # psi leaves are un-stacked (no K axis): the masks broadcast directly
             masked_j = jax.tree.map(lambda m, p: m * p, masks[j], psi_j)
             psi_acc = masked_j if psi_acc is None else jax.tree.map(jnp.add, psi_acc, masked_j)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import DATAGEN
+
 
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
@@ -73,7 +75,8 @@ class MarkovStream:
         """
         fn = self._stacked_fns.get(n_steps)
         if fn is None:
-            def stacked(start):
+            @jax.named_scope(DATAGEN)
+            def stacked(start):  # compiled as jit_stacked: trace readers find it by that name
                 steps = start + jnp.arange(n_steps)
                 return jax.vmap(self._batch_toks)(steps)
 

@@ -34,6 +34,7 @@ chosen spans so every recovery path is provable end-to-end.
 from __future__ import annotations
 
 import collections
+import functools
 from typing import Any, Callable
 
 import jax
@@ -41,6 +42,7 @@ import numpy as np
 
 from repro.engine.recovery import RecoveryPolicy, TrainingAborted
 from repro.engine.superstep import effective_rounds_per_dispatch
+from repro.tracing import CHECKPOINT, DATAGEN, DISPATCH, DRAIN, RECOVERY, RUN_ROUNDS
 
 PyTree = Any
 
@@ -72,6 +74,12 @@ def _with_round(state: PyTree, value: int) -> PyTree:
     return _replace(state, round=new)
 
 
+def _span(name: str):
+    """Decorator: each call runs inside the host span ``name``."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
+
+
+@_span(RUN_ROUNDS)
 def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
                rounds: int, *, start: int = 0,
                rounds_per_dispatch: int | str = 1,
@@ -154,6 +162,11 @@ def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
       span-stacked batches or the state before the dispatch. Test/chaos
       only; None is a no-op.
 
+    Every phase runs inside a host span of :mod:`repro.tracing` (the whole
+    call, data generation, each dispatch with its first round and R, each
+    drain, checkpoints and recovery), so a profiler trace names what the
+    host was doing while the device idled.
+
     ``telemetry`` (optional dict) is filled with the resolved dispatch plan:
     ``rounds_per_dispatch``, ``dispatches`` (incremented as they happen),
     ``in_program_checkpoints`` — plus the recovery counters ``rollbacks``,
@@ -190,11 +203,13 @@ def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
 
         engine.checkpoint_sink = _sink
 
+    @_span(CHECKPOINT)
     def flush_checkpoints() -> None:
         while ckpt_stash:
             st = jax.tree.map(np.asarray, ckpt_stash.popleft())
             on_state(int(st["round"]) - 1, st)
 
+    @_span(DRAIN)
     def drain_one() -> None:
         r0, n, loss, ev, cb, aw, st, hl = pending.popleft()
         hls = None if hl is None else np.atleast_1d(np.asarray(jax.device_get(hl)))
@@ -243,40 +258,45 @@ def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
                     break
                 R = effective_rounds_per_dispatch(R0, rounds - r0, cadence,
                                                   start=r0)
-                masks = (np.asarray(participation_for(r0, R), np.float32)
-                         if participation_for is not None else None)
-                if R == 1 and eval_batches_for is None and not in_prog_ckpt:
-                    # classic path: single-round dispatch + optional host eval
-                    b = batches_for(r0)
-                    if inject is not None:
-                        b1, state = inject(
-                            r0, 1, jax.tree.map(lambda x: np.asarray(x)[None], b),
-                            state)
-                        b = jax.tree.map(lambda x: x[0], b1)
-                    state, info = engine.step(
-                        state, b,
-                        participation=None if masks is None else masks[0])
-                    ev = eval_fn(state, r0) if eval_fn is not None else None
-                    loss, cb = info["loss"], info["comm_bytes"]
-                    aw, st = info.get("active_workers"), info.get("staleness")
-                    hl = info.get("health")
-                else:
-                    if span_batches_for is not None:
+                # classic path: single-round dispatch + optional host eval
+                classic = R == 1 and eval_batches_for is None and not in_prog_ckpt
+                with jax.profiler.TraceAnnotation(DATAGEN):
+                    masks = (np.asarray(participation_for(r0, R), np.float32)
+                             if participation_for is not None else None)
+                    if classic:
+                        b = batches_for(r0)
+                    elif span_batches_for is not None:
                         batches = span_batches_for(r0, R)
                     else:
                         batches = jax.tree.map(
                             lambda *bs: np.stack([np.asarray(b) for b in bs]),
                             *[batches_for(r0 + i) for i in range(R)])
-                    if inject is not None:
-                        batches, state = inject(r0, R, batches, state)
                     eb = (eval_batches_for(r0, R)
                           if eval_batches_for is not None else None)
+                if classic:
+                    if inject is not None:
+                        b1, state = inject(
+                            r0, 1, jax.tree.map(lambda x: np.asarray(x)[None], b),
+                            state)
+                        b = jax.tree.map(lambda x: x[0], b1)
+                    with jax.profiler.TraceAnnotation(DISPATCH, round=r0, rounds=R):
+                        state, info = engine.step(
+                            state, b,
+                            participation=None if masks is None else masks[0])
+                        ev = eval_fn(state, r0) if eval_fn is not None else None
+                    loss, cb = info["loss"], info["comm_bytes"]
+                    aw, st = info.get("active_workers"), info.get("staleness")
+                    hl = info.get("health")
+                else:
+                    if inject is not None:
+                        batches, state = inject(r0, R, batches, state)
                     flags = (np.asarray([(r0 + i + 1) % on_state_every == 0
                                          for i in range(R)], bool)
                              if in_prog_ckpt else None)
-                    state, out = engine.superstep(state, batches, eb,
-                                                  participation=masks,
-                                                  ckpt_flags=flags)
+                    with jax.profiler.TraceAnnotation(DISPATCH, round=r0, rounds=R):
+                        state, out = engine.superstep(state, batches, eb,
+                                                      participation=masks,
+                                                      ckpt_flags=flags)
                     ev = out.get("eval_loss")
                     loss, cb = out["loss"], out["comm_bytes"]
                     aw, st = out.get("active_workers"), out.get("staleness")
@@ -290,7 +310,8 @@ def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
                 if cadence and (r0 + R) % on_state_every == 0:
                     while pending:  # CSV must never lag a saved checkpoint
                         drain_one()
-                    on_state(r0 + R - 1, state)
+                    with jax.profiler.TraceAnnotation(CHECKPOINT):
+                        on_state(r0 + R - 1, state)
                 while len(pending) > max_in_flight:
                     drain_one()
                 if in_prog_ckpt and not pending:
@@ -303,48 +324,49 @@ def run_rounds(engine, state, batches_for: Callable[[int], PyTree],
                 drain_one()
             done = True
         except _Fault as fault:
-            # Everything in flight descends from the poisoned state: drop
-            # the metric buffers unread and the stashed checkpoint carries
-            # unwritten (a poisoned carry must never become a "valid"
-            # checkpoint on disk).
-            pending.clear()
-            ckpt_stash.clear()
-            if rollbacks_left <= 0:
-                if (recovery.scale_lr is not None
-                        and lr_halvings < recovery.max_lr_halvings):
-                    lr_halvings += 1
-                    lr_scale *= recovery.lr_backoff
-                    new_engine = recovery.scale_lr(lr_scale)
-                    if new_engine is not None:
-                        if in_prog_ckpt:
-                            engine.checkpoint_sink = None
-                            new_engine.checkpoint_sink = _sink
-                        engine = new_engine
-                    rollbacks_left = recovery.max_rollbacks
-                    if telemetry is not None:
-                        telemetry["lr_scale"] = lr_scale
-                    print(f"recovery: rollback budget exhausted; inner LR "
-                          f"backed off to x{lr_scale:g}")
-                else:
+            with jax.profiler.TraceAnnotation(RECOVERY):
+                # Everything in flight descends from the poisoned state: drop
+                # the metric buffers unread and the stashed checkpoint carries
+                # unwritten (a poisoned carry must never become a "valid"
+                # checkpoint on disk).
+                pending.clear()
+                ckpt_stash.clear()
+                if rollbacks_left <= 0:
+                    if (recovery.scale_lr is not None
+                            and lr_halvings < recovery.max_lr_halvings):
+                        lr_halvings += 1
+                        lr_scale *= recovery.lr_backoff
+                        new_engine = recovery.scale_lr(lr_scale)
+                        if new_engine is not None:
+                            if in_prog_ckpt:
+                                engine.checkpoint_sink = None
+                                new_engine.checkpoint_sink = _sink
+                            engine = new_engine
+                        rollbacks_left = recovery.max_rollbacks
+                        if telemetry is not None:
+                            telemetry["lr_scale"] = lr_scale
+                        print(f"recovery: rollback budget exhausted; inner LR "
+                              f"backed off to x{lr_scale:g}")
+                    else:
+                        raise TrainingAborted(
+                            f"health flag {fault.code} at round {fault.round}: "
+                            f"rollback and LR-backoff budgets exhausted") from None
+                rollbacks_left -= 1
+                restored = recovery.restore()
+                if restored is None:
                     raise TrainingAborted(
-                        f"health flag {fault.code} at round {fault.round}: "
-                        f"rollback and LR-backoff budgets exhausted") from None
-            rollbacks_left -= 1
-            restored = recovery.restore()
-            if restored is None:
-                raise TrainingAborted(
-                    f"health flag {fault.code} at round {fault.round} but no "
-                    f"valid checkpoint to roll back to") from None
-            state, ckpt_round = restored
-            skip_to = fault.round + 1
-            state = _with_round(state, skip_to)
-            if telemetry is not None:
-                telemetry["rollbacks"] += 1
-                telemetry["skipped_rounds"] += skip_to - ckpt_round
-            print(f"recovery: round {fault.round} flagged (code {fault.code}); "
-                  f"rolled back to checkpoint round {ckpt_round}, resuming at "
-                  f"round {skip_to}")
-            r0 = skip_to
+                        f"health flag {fault.code} at round {fault.round} but no "
+                        f"valid checkpoint to roll back to") from None
+                state, ckpt_round = restored
+                skip_to = fault.round + 1
+                state = _with_round(state, skip_to)
+                if telemetry is not None:
+                    telemetry["rollbacks"] += 1
+                    telemetry["skipped_rounds"] += skip_to - ckpt_round
+                print(f"recovery: round {fault.round} flagged (code {fault.code}); "
+                      f"rolled back to checkpoint round {ckpt_round}, resuming at "
+                      f"round {skip_to}")
+                r0 = skip_to
     if in_prog_ckpt:
         # the sink belongs to THIS run; drop it so a later run without
         # in-program checkpoints can never fire a stale on_state

@@ -45,6 +45,7 @@ from repro.engine.state import TrainState
 from repro.engine.superstep import build_superstep_fn
 from repro.models.api import Model
 from repro.optim import OptimizerConfig
+from repro.tracing import EVAL
 
 PyTree = Any
 
@@ -128,7 +129,7 @@ class TrainEngine:
         from repro.kernels.partition import kernel_partitioning
 
         def eval_loss_fn(params, batch):
-            with kernel_partitioning(self.kernel_parts):
+            with kernel_partitioning(self.kernel_parts), jax.named_scope(EVAL):
                 return model.loss(params, batch)[0]
         # In-program checkpoint plumbing: the superstep's io_callback lands
         # in _emit_checkpoint, which forwards to whatever sink the driver

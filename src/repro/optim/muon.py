@@ -36,6 +36,7 @@ from repro.models.common import shard_hint
 from repro.optim.adamw import scale_by_adam
 from repro.optim.base import Optimizer, OptimizerConfig, descend
 from repro.optim.transform import Transform, chain, partition
+from repro.tracing import NEWTON_SCHULZ
 
 PyTree = Any
 
@@ -75,6 +76,7 @@ def _ns_body(X: jax.Array) -> jax.Array:
     return a * X + B @ X
 
 
+@jax.named_scope(NEWTON_SCHULZ)
 def newton_schulz(G: jax.Array, iters: int = 5, eps: float = 1e-7) -> jax.Array:
     """Orthogonalize the trailing two dims of G via quintic Newton–Schulz.
 
@@ -99,6 +101,7 @@ def newton_schulz(G: jax.Array, iters: int = 5, eps: float = 1e-7) -> jax.Array:
     return X.reshape((*batch, m, n)).astype(orig_dtype)
 
 
+@jax.named_scope(NEWTON_SCHULZ)
 def newton_schulz_pallas(G: jax.Array, iters: int = 5, eps: float = 1e-7) -> jax.Array:
     """Same contract as :func:`newton_schulz` but with Pallas-kernel matmuls."""
     from repro.kernels.ops import ns_orthogonalize
