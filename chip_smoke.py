@@ -10,7 +10,8 @@ Every phase goes through the normal entry points in this one process
 30 layers, d_model 576, vocab 49152, random weights from a seed.
 
   (a) Muon inner, K=4 workers vmapped on the chip, H=4, 2 rounds, 4
-      sequences of 2048 per worker (XLA attention, jnp Newton-Schulz);
+      sequences of 2048 per worker (the default ``--attn-impl auto``, which
+      is the flash kernel at 2048 on a TPU; jnp Newton-Schulz);
   (b) the same run with the AdamW inner optimizer;
   (c) every training kernel compiled by Mosaic: 4-bit wire quantization
       with error feedback, Pallas flash attention, Pallas Newton-Schulz and

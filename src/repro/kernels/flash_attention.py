@@ -17,7 +17,10 @@ quantization (``kernels/quantize.py``):
   reason.
 * **Online softmax**: fp32 ``m``/``l``/``acc`` accumulators live in VMEM
   scratch across the kv-block sweep; the epilogue normalizes once and also
-  emits the per-row logsumexp for the backward pass.
+  emits the per-row logsumexp for the backward pass. The MXU takes q, k, v,
+  do and the probability and ds tiles in the operands' own dtype (bf16 in
+  training) and accumulates in fp32; scores, softmax statistics and the
+  dq/dk/dv accumulators stay fp32.
 * **Full-block skipping**: the grid is built from an explicit *visit
   schedule* (:func:`attention_schedule`) carried in via scalar prefetch —
   kv blocks entirely above the causal diagonal or outside the sliding
@@ -182,11 +185,16 @@ def _mask_and_positions(qi, kj, bq, bkv, G, causal, window):
     return mask
 
 
+def _dot(a, b, contract):
+    """MXU product of two operands in their own dtype (bf16 in training),
+    accumulated in f32; ``contract`` = (a's dim, b's dim)."""
+    return jax.lax.dot_general(a, b, (((contract[0],), (contract[1],)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _scores(q_ref, k_ref, bq, G, hd, scale):
-    q = q_ref[0].reshape(G * bq, hd).astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32) * scale
+    q = q_ref[0].reshape(G * bq, hd)
+    return _dot(q, k_ref[0], (1, 1)) * scale
 
 
 def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -211,9 +219,8 @@ def _fwd_kernel(sched_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    v = v_ref[0].astype(jnp.float32)
-    acc_new = acc_scr[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    v = v_ref[0]
+    acc_new = acc_scr[...] * corr + _dot(p.astype(v.dtype), v, (1, 0))
     m_scr[...] = m_new
     l_scr[...] = l_new
     acc_scr[...] = acc_new
@@ -245,14 +252,11 @@ def _dq_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     p = _probs(sched_ref, q_ref, k_ref, lse_ref, g, bq=bq, bkv=bkv, G=G,
                hd=hd, causal=causal, window=window, scale=scale)
-    do = do_ref[0].reshape(G * bq, hd).astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    do = do_ref[0].reshape(G * bq, hd)
+    dp = _dot(do, v_ref[0], (1, 1))
     ds = p * (dp - dl_ref[0].reshape(G * bq, 1))
-    k = k_ref[0].astype(jnp.float32)
-    dq_scr[...] += scale * jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    k = k_ref[0]
+    dq_scr[...] += scale * _dot(ds.astype(k.dtype), k, (1, 0))
 
     @pl.when(sched_ref[g, 3] == 1)
     def _epilogue():
@@ -271,16 +275,12 @@ def _dkv_kernel(sched_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     p = _probs(sched_ref, q_ref, k_ref, lse_ref, g, bq=bq, bkv=bkv, G=G,
                hd=hd, causal=causal, window=window, scale=scale)
-    do = do_ref[0].reshape(G * bq, hd).astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    do = do_ref[0].reshape(G * bq, hd)
+    dv_scr[...] += _dot(p.astype(do.dtype), do, (0, 0))
+    dp = _dot(do, v_ref[0], (1, 1))
     ds = p * (dp - dl_ref[0].reshape(G * bq, 1))
-    q = q_ref[0].reshape(G * bq, hd).astype(jnp.float32)
-    dk_scr[...] += scale * jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    q = q_ref[0].reshape(G * bq, hd)
+    dk_scr[...] += scale * _dot(ds.astype(q.dtype), q, (0, 0))
 
     @pl.when(sched_ref[g, 3] == 1)
     def _epilogue():

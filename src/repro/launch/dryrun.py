@@ -95,8 +95,10 @@ def run_one(arch: str, shape: str, multi_pod: bool, sync_interval: int = 30,
     from repro.launch.sharding import kernel_specs
 
     kparts = kernel_specs(mesh, cfg0)
-    uses_pallas = (attn_impl == "pallas" or ns_impl == "pallas"
-                   or outer_kernel or wire_impl == "pallas")
+    from repro.models.attention import attention_path
+
+    uses_pallas = (attention_path(cfg0, INPUT_SHAPES[shape].seq_len) == "pallas"
+                   or ns_impl == "pallas" or outer_kernel or wire_impl == "pallas")
     from repro.kernels.autotune import autotune_evidence
 
     kernels_evidence = {
@@ -187,14 +189,15 @@ def run_one(arch: str, shape: str, multi_pod: bool, sync_interval: int = 30,
                 )
 
                 S = INPUT_SHAPES[shape].seq_len
+                path = attention_path(cfg, S)
                 rec["attention"] = {
                     "impl": cfg.attn_impl,
+                    "path": path,
                     "block_q": clamp_block(cfg.attn_block_q, S),
                     "block_kv": clamp_block(cfg.attn_block_kv, S),
-                    # block-granular execution: always for pallas, above the
-                    # threshold for xla
-                    "blockwise": bool(cfg.attn_impl == "pallas"
-                                      or S >= cfg.blockwise_threshold),
+                    # block-granular execution: the flash kernel and the
+                    # blockwise XLA path
+                    "blockwise": path != "dense",
                     # fraction of the block grid the visit schedule executes
                     # (causal diagonal + sliding window skipping)
                     "visited_fraction": round(visited_fraction(
@@ -445,9 +448,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "records measured vs modeled bytes)")
     ap.add_argument("--bits", type=int, default=4)
     ap.add_argument("--topk-frac", type=float, default=0.01)
-    ap.add_argument("--attn-impl", default="xla", choices=["xla", "pallas"],
-                    help="attention backend for the lowered plans; 'pallas' "
-                         "shard_maps the fused kernel over the mesh "
+    ap.add_argument("--attn-impl", default="xla",
+                    choices=["auto", "xla", "pallas"],
+                    help="attention backend for the lowered plans ('auto' "
+                         "resolves as the model's attention_path does); "
+                         "'pallas' shard_maps the fused kernel over the mesh "
                          "(batch x kv-heads -> 'data' x 'model'), so it "
                          "lowers on the 512-device world too")
     ap.add_argument("--ns-impl", default="jnp", choices=["jnp", "pallas"],

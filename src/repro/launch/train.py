@@ -436,10 +436,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "--rounds-per-dispatch chunking sees identical "
                          "faults)")
     ap.add_argument("--ns-impl", default="jnp", choices=["jnp", "pallas"])
-    ap.add_argument("--attn-impl", default="xla", choices=["xla", "pallas"],
-                    help="attention backend: 'xla' (dense/blockwise) or "
-                         "'pallas' (fused flash-attention kernel; interpret "
-                         "mode off-TPU). Both run on a --mesh: pallas is "
+    ap.add_argument("--attn-impl", default="auto",
+                    choices=["auto", "xla", "pallas"],
+                    help="attention backend: 'auto' (the flash kernel on a "
+                         "TPU at seq >= repro.models.attention.FLASH_MIN_SEQ "
+                         "and a multiple of 128, else xla), 'xla' (dense/blockwise) or 'pallas' "
+                         "(fused flash-attention kernel; interpret mode "
+                         "off-TPU). All run on a --mesh: pallas is "
                          "shard_mapped over the mesh by the engine's kernel "
                          "routing")
     ap.add_argument("--mesh", default=None,

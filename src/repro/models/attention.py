@@ -17,6 +17,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+from repro.kernels.backend import pallas_interpret
 from repro.models.common import ModelConfig, apply_rope, dense_init, rms_norm, shard_hint
 
 PyTree = Any
@@ -79,13 +81,37 @@ def _gqa_out(probs: jax.Array, v: jax.Array) -> jax.Array:
 # default for ModelConfig.blockwise_threshold (kept as a module constant for
 # external callers; the config field is what `attend` consults)
 BLOCKWISE_THRESHOLD = 4096
+# Shortest sequence at which attn_impl='auto' takes the flash kernel on a
+# TPU. Below it XLA's dense softmax was as fast or faster (fwd+bwd of one
+# smollm-135m layer's attention, bf16, on a v5e; docs/architecture.md).
+FLASH_MIN_SEQ = 1024
+
+
+def attention_path(cfg: ModelConfig, S: int) -> str:
+    """The path ``attend`` runs for a full sequence of length S:
+    ``'pallas'`` (the flash kernel), ``'blockwise'`` or ``'dense'`` (XLA).
+
+    ``attn_impl='auto'`` takes the flash kernel where it runs compiled (a
+    TPU, where ``pallas_interpret()`` is False) at S >= FLASH_MIN_SEQ with
+    S a multiple of 128 (so its blocks, clamped to divide S, stay whole
+    128-row tiles), and the XLA paths otherwise; ``'pallas'`` and ``'xla'``
+    force their path.
+    """
+    impl = cfg.attn_impl
+    if impl == "auto":
+        flash = not pallas_interpret() and S >= FLASH_MIN_SEQ and S % 128 == 0
+        impl = "pallas" if flash else "xla"
+    if impl == "pallas":
+        return "pallas"
+    return "blockwise" if S >= cfg.blockwise_threshold else "dense"
 
 
 def attend(p: PyTree, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
            causal: bool = True, return_kv: bool = False):
     """Full-sequence self-attention (training / prefill).
 
-    Backend dispatch (``cfg.attn_impl``):
+    Backend dispatch (``cfg.attn_impl``, resolved by :func:`attention_path`;
+    ``'auto'`` picks one of the two below by backend and sequence length):
 
     * ``'pallas'`` — the fused flash-attention kernel
       (:func:`repro.kernels.flash_attention.gqa_flash_attention`): GQA-native
@@ -102,7 +128,9 @@ def attend(p: PyTree, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
       differentiable, O(S * block) memory.
 
     Both non-dense paths assume rows attend by absolute position
-    (``positions == arange(S)``, the training/prefill layout).
+    (``positions == arange(S)``, the training/prefill layout). The core,
+    between the projections, runs under the device scope
+    ``repro.attention``.
 
     ``return_kv=True`` additionally returns the post-RoPE ``(k, v)``
     projections ([B, S, KV, hd] each) — exactly what ``attend_decode``
@@ -116,36 +144,43 @@ def attend(p: PyTree, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     q = shard_hint(q, "attn_kv")
     k = shard_hint(k, "attn_kv")
     v = shard_hint(v, "attn_kv")
-    S = x.shape[1]
-    B = x.shape[0]
-    if cfg.attn_impl == "pallas":
+    with jax.named_scope(tracing.ATTENTION):
+        o = _attention_core(cfg, q, k, v, positions, causal, x.dtype)
+    out = o @ p["wo"].astype(cfg.compute_dtype)
+    if return_kv:
+        return out, (k, v)
+    return out
+
+
+def _attention_core(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
+                    positions: jax.Array, causal: bool, probs_dtype) -> jax.Array:
+    """q [B,S,H,hd], k/v [B,S,KV,hd] -> o [B,S,H*hd] on :func:`attention_path`
+    (the dense path rounds its probabilities to ``probs_dtype``)."""
+    B, S = q.shape[:2]
+    path = attention_path(cfg, S)
+    if path == "pallas":
         from repro.kernels.flash_attention import gqa_flash_attention
 
         o = gqa_flash_attention(
             q, k, v, causal=causal,
             window=cfg.sliding_window if causal else 0,
             block_q=cfg.attn_block_q, block_kv=cfg.attn_block_kv)
-        o = o.reshape(B, S, -1)
-    elif S >= cfg.blockwise_threshold:
+        return o.reshape(B, S, -1)
+    if path == "blockwise":
         o = _blockwise_attention(cfg, q, k, v, causal=causal,
                                  block_q=cfg.attn_block_q,
                                  block_kv=cfg.attn_block_kv)
-        o = o.reshape(B, S, -1)
-    else:
-        scores = _gqa_scores(q, k).astype(jnp.float32)  # [B,KV,G,S,S]
-        if causal:
-            i = positions if positions.ndim == 1 else positions[0]
-            mask = i[:, None] >= i[None, :]
-            if cfg.sliding_window:
-                mask &= i[:, None] - i[None, :] < cfg.sliding_window
-            scores = jnp.where(mask[None, None, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        probs = shard_hint(probs, "attn_probs")
-        o = _gqa_out(probs, v)
-    out = o @ p["wo"].astype(cfg.compute_dtype)
-    if return_kv:
-        return out, (k, v)
-    return out
+        return o.reshape(B, S, -1)
+    scores = _gqa_scores(q, k).astype(jnp.float32)  # [B,KV,G,S,S]
+    if causal:
+        i = positions if positions.ndim == 1 else positions[0]
+        mask = i[:, None] >= i[None, :]
+        if cfg.sliding_window:
+            mask &= i[:, None] - i[None, :] < cfg.sliding_window
+        scores = jnp.where(mask[None, None, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(probs_dtype)
+    probs = shard_hint(probs, "attn_probs")
+    return _gqa_out(probs, v)
 
 
 def _blockwise_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,

@@ -66,9 +66,11 @@ class ModelConfig:
     # attention variant
     sliding_window: int = 0  # 0 = full causal attention
     # attention execution backend: 'xla' (dense below blockwise_threshold,
-    # online-softmax blockwise above) or 'pallas' (fused flash-attention
+    # online-softmax blockwise above), 'pallas' (fused flash-attention
     # kernel, interpret mode off-TPU; shard_mapped over the mesh by the
     # kernel-partitioning routing, so it lowers on multi-device worlds too)
+    # or 'auto' (models.attention.attention_path: 'pallas' on a TPU from
+    # FLASH_MIN_SEQ, else 'xla'; the train CLI's default)
     attn_impl: str = "xla"
     blockwise_threshold: int = 4096  # seqs >= this switch xla to blockwise
     attn_block_q: int = 512  # q-block rows per attention tile

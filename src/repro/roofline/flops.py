@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro.models.attention import attention_path
 from repro.models.common import ModelConfig
 
 
@@ -38,8 +39,7 @@ def _attn_flops(cfg: ModelConfig, S: int, T: int, kv_len: int | None = None) -> 
     full_seq = T == S and kv_len is None
     # the Pallas kernel runs the block schedule at every length; the XLA
     # path only above the blockwise threshold
-    blocked = cfg.attn_impl == "pallas" or S >= cfg.blockwise_threshold
-    if full_seq and blocked:
+    if full_seq and attention_path(cfg, S) != "dense":
         from repro.kernels.flash_attention import visited_fraction
 
         # block-granular skipping: both impls visit exactly this fraction

@@ -13,12 +13,16 @@ shows both:
   by what the host was doing in it. With the profiler off a span costs
   under a microsecond.
 
-Scopes nest: ``NEWTON_SCHULZ`` lies inside ``INNER_OPT``, and
+Scopes nest: ``ATTENTION`` lies inside ``FWD_BWD`` (and ``EVAL``),
+``NEWTON_SCHULZ`` inside ``INNER_OPT``, and
 ``PSEUDOGRAD``, ``REDUCE`` and ``OUTER_UPDATE`` inside ``OUTER_SYNC``.
 """
 
 # device scopes
 FWD_BWD = "repro.fwd_bwd"  # forward, backward and rematerialisation of each inner step
+# the attention core of each layer, flash kernel or XLA; kept out of
+# DEVICE_SCOPES until the benchmark's recorded scope trace carries it
+ATTENTION = "repro.attention"
 INNER_OPT = "repro.inner_opt"  # the inner optimizer: Muon (momentum, NS, AdamW leaves) or AdamW
 NEWTON_SCHULZ = "repro.newton_schulz"  # Muon's orthogonalisation
 OUTER_SYNC = "repro.outer_sync"  # the whole sync, the three stages below

@@ -146,6 +146,65 @@ def test_stacked_layer_lm_loss_and_grads_match():
                                    rtol=1e-3, atol=1e-4)
 
 
+@pytest.mark.parametrize("S", [16, 256, 1024, 2048, 4096, 8192])
+def test_auto_takes_xla_off_tpu(S):
+    """Off the TPU 'auto' is 'xla' at every length (dense below the
+    blockwise threshold), so CPU numerics do not move."""
+    cfg = ModelConfig(attn_impl="auto")
+    want = "blockwise" if S >= cfg.blockwise_threshold else "dense"
+    assert A.attention_path(cfg, S) == want
+    assert A.attention_path(cfg.replace(attn_impl="xla"), S) == want
+
+
+def test_auto_takes_flash_on_tpu_from_the_crossover(monkeypatch):
+    """Where kernels compile (pallas_interpret() False) 'auto' takes the
+    flash kernel from FLASH_MIN_SEQ (the smollm cell's 2048 among them) and
+    the dense path below; 'xla' and 'pallas' still force their path."""
+    monkeypatch.setattr(A, "pallas_interpret", lambda: False)
+    cfg = ModelConfig(attn_impl="auto")
+    assert A.FLASH_MIN_SEQ <= 2048
+    for S in (2048, A.FLASH_MIN_SEQ, 8192):
+        assert A.attention_path(cfg, S) == "pallas"
+    assert A.attention_path(cfg, A.FLASH_MIN_SEQ // 2) == "dense"
+    assert A.attention_path(cfg, 1500) == "dense"  # blocks would clamp to 4
+    assert A.attention_path(cfg.replace(attn_impl="xla"), 2048) == "dense"
+    assert A.attention_path(cfg.replace(attn_impl="pallas"), 64) == "pallas"
+
+
+def test_bf16_flash_is_no_further_from_f32_than_dense():
+    """bf16 operands on the MXU with f32 scores and accumulators: the
+    kernel's output and grads lie no further from the float32 oracle (norm
+    of the error) than the dense XLA path's, which rounds its scores to
+    bf16."""
+    S, H, KV, hd = 128, 6, 2, 64
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(S, H, KV, hd))
+    base = ModelConfig(n_heads=H, n_kv_heads=KV, d_model=H * hd, head_dim=hd,
+                       dtype="bfloat16", attn_block_q=32, attn_block_kv=64)
+    pos = jnp.arange(S)
+
+    def core(impl):
+        cfg = base.replace(attn_impl=impl)
+        return lambda q, k, v: A._attention_core(cfg, q, k, v, pos, True,
+                                                 jnp.bfloat16)
+
+    def oracle(q, k, v):
+        return ref.gqa_attention_ref(*(x.astype(jnp.float32) for x in (q, k, v))
+                                     ).reshape(2, S, -1)
+
+    def out_and_grads(fn):
+        o = jax.jit(fn)(q, k, v)
+        g = jax.jit(jax.grad(lambda q, k, v: jnp.sum(jnp.sin(
+            fn(q, k, v).astype(jnp.float32))), argnums=(0, 1, 2)))(q, k, v)
+        return [np.asarray(x, np.float32) for x in (o, *g)]
+
+    with jax.default_matmul_precision("highest"):
+        want = out_and_grads(oracle)
+    flash, dense = out_and_grads(core("pallas")), out_and_grads(core("xla"))
+    for name, w, f, d in zip(("o", "dq", "dk", "dv"), want, flash, dense):
+        err_f, err_d = np.linalg.norm(f - w), np.linalg.norm(d - w)
+        assert err_f <= err_d, (name, err_f, err_d)
+
+
 # ---------------------------------------------------------------------------
 # Block skipping: proofs on the grid itself, and skipped == unskipped
 # ---------------------------------------------------------------------------

@@ -21,7 +21,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import _flash_fn, _paged_decode_pallas
+from repro.kernels.flash_attention import (
+    DEFAULT_BLOCK_KV,
+    DEFAULT_BLOCK_Q,
+    _flash_fn,
+    _paged_decode_pallas,
+)
 from repro.kernels.matmul import matmul_epilogue
 from repro.kernels.outer_update import fused_nesterov_update
 from repro.kernels.quantize import rowwise_dequantize, rowwise_quantize
@@ -73,6 +78,22 @@ def test_flash_fwd_and_bwd_compile(one_chip, dtype):
     dt = jnp.dtype(dtype)
     _compile(fwd_bwd, one_chip, ((B * KV, G, S, HD), dt),
              ((B * KV, S, HD), dt), ((B * KV, S, HD), dt))
+
+
+def test_flash_vmapped_bf16_compiles_at_default_blocks(one_chip):
+    """The training cell's call: bf16 operands, vmapped over K=4 workers,
+    [B*KV, G, S, hd] per worker, at the default blocks; forward, dq and
+    dk/dv sweeps."""
+    fn = _flash_fn(True, 0, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV, HD ** -0.5,
+                   False, True)
+
+    def fwd_bwd(q, k, v):
+        o, vjp = jax.vjp(fn, q, k, v)
+        return o, vjp(jnp.sin(o.astype(jnp.float32)).astype(o.dtype))
+
+    dt = jnp.bfloat16
+    _compile(jax.vmap(fwd_bwd), one_chip, ((K, B * KV, G, S, HD), dt),
+             ((K, B * KV, S, HD), dt), ((K, B * KV, S, HD), dt))
 
 
 def test_paged_decode_compiles(one_chip):

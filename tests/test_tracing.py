@@ -57,6 +57,10 @@ def test_round_program_carries_every_scope(inner):
     for scope in in_program:
         assert any(scope in n for n in names), f"{scope} missing from the round program"
     assert (inner == "muon") == any(tracing.NEWTON_SCHULZ in n for n in names)
+    # attention runs in every forward: the inner steps' and the eval's
+    attention = [n for n in names if tracing.ATTENTION in n]
+    assert any(tracing.FWD_BWD in n for n in attention)
+    assert any(tracing.EVAL in n for n in attention)
     # Newton-Schulz is part of the inner optimizer, the sync's stages of the sync
     for inner_scope, outer_scope in [(tracing.NEWTON_SCHULZ, tracing.INNER_OPT),
                                      (tracing.PSEUDOGRAD, tracing.OUTER_SYNC),
