@@ -15,8 +15,9 @@ Every phase goes through the normal entry points in this one process
   (b) the same run with the AdamW inner optimizer;
   (c) every training kernel compiled by Mosaic: 4-bit wire quantization
       with error feedback, Pallas flash attention, Pallas Newton-Schulz and
-      the fused outer update; each kernel is also checked against its jnp
-      oracle (``repro.kernels.ref``) on a small input;
+      the fused outer update; each kernel, and Mamba2's fused SSD, is also
+      checked against its jnp oracle (``repro.kernels.ref``, and
+      ``ssm.ssd_intra_chunk_xla`` for the SSD) on a small input;
   (d) paged serving with the Pallas paged-decode kernel, a handful of
       requests, and the kernel's own oracle check.
 
@@ -179,6 +180,46 @@ def train_kernel_errors() -> dict:
     got = ops.nesterov_update(t, p, u, lr=0.7, momentum=0.9)
     want = ref.nesterov_update_ref(t, p, u, lr=0.7, momentum=0.9)
     errs["outer_update"] = max(_rel_err(a, b) for a, b in zip(got, want))
+    errs.update(ssd_errors())
+    return errs
+
+
+def ssd_errors() -> dict:
+    """The fused intra-chunk SSD kernel, forward and VJP, against its jnp
+    expression (``ssm.ssd_intra_chunk_xla``) in float32 at mamba2-370m's
+    chunk 256, head dim 64 and state 128, with 16 heads (two grid steps of
+    heads per chunk, so the backward's sums over steps are checked)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.ssd import ssd_intra_chunk
+    from repro.models.ssm import ssd_intra_chunk_xla
+
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    B, C, Q, H, P, N = 1, 2, 256, 16, 64, 128
+    xc, Bc, Cc = (jax.random.normal(kk, shape, jnp.bfloat16).astype(jnp.float32)
+                  for kk, shape in ((ks[0], (B, C, Q, H, P)), (ks[1], (B, C, Q, N)),
+                                    (ks[2], (B, C, Q, N))))
+    dt = jax.nn.softplus(jax.random.normal(ks[3], (B, C, Q, H)) - 3.0)
+    dA = dt * -jnp.linspace(1.0, 16.0, H)
+    dy = jax.random.normal(ks[4], (B, C, Q, H, P))
+
+    def kernel(xc, dt, Bc, Cc, dA):
+        return ssd_intra_chunk(xc, dt, Bc, Cc, jnp.cumsum(dA, axis=2))
+
+    def fwd_bwd(fn):
+        def run(*args):
+            y, vjp = jax.vjp(fn, *args)
+            return y, vjp(dy)
+        return jax.jit(run)
+
+    args = (xc, dt, Bc, Cc, dA)
+    with jax.default_matmul_precision("highest"):
+        want_y, want_g = fwd_bwd(ssd_intra_chunk_xla)(*args)
+    got_y, got_g = fwd_bwd(kernel)(*args)
+    errs = {"ssd_fwd": _rel_err(got_y, want_y)}
+    for name, got, want in zip(("dx", "ddt", "dB", "dC", "ddA"), got_g, want_g):
+        errs[f"ssd_{name}"] = _rel_err(got, want)
     return errs
 
 

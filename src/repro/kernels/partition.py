@@ -6,10 +6,10 @@ every fused kernel used to fall back to XLA on the production mesh. The fix
 is the maxtext-DiLoCo combination: wrap the kernel call in
 ``jax.shard_map`` with explicit PartitionSpecs, so GSPMD sees
 an opaque per-device region and each device runs the kernel on its local
-block. All five kernels are embarrassingly parallel over the axes we shard
-(batch*kv-head rows for flash attention, quantize rows, stacked
-Newton-Schulz matrices, elementwise outer updates in the state's own
-layout, serving batch slots), so the shard_mapped result is bitwise-identical to the
+block. All six kernels are embarrassingly parallel over the axes we shard
+(batch*kv-head rows for flash attention, sequences for the SSD,
+quantize rows, stacked Newton-Schulz matrices, elementwise outer updates in
+the state's own layout, serving batch slots), so the shard_mapped result is bitwise-identical to the
 single-device call — padding to block multiples happens *inside* the mapped
 region, on local shapes, so splitting an axis never changes any row's
 arithmetic.

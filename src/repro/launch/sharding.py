@@ -217,6 +217,8 @@ def kernel_specs(mesh: Mesh | None, cfg=None):
       sharding is what keeps the donated TrainState aliased.
     * paged decode: batch slots (plus their page-table rows) over ('data',),
       KV pool and visit schedules replicated.
+    * intra-chunk SSD: the batch axis over ('data',), K via 'pod' as for
+      flash.
 
     Returns None on single-device worlds (kernels keep their plain
     single-device pallas_call path).

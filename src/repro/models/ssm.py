@@ -18,6 +18,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+from repro.kernels import ssd
+from repro.kernels.backend import pallas_interpret
 from repro.models.common import ModelConfig, dense_init, rms_norm
 
 PyTree = Any
@@ -69,8 +72,36 @@ def _split_proj(p: PyTree, cfg: ModelConfig, x: jax.Array):
     return z, xBC, dt  # dt: [B, S, H]
 
 
+def ssd_intra_chunk_xla(xc: jax.Array, dt_c: jax.Array, Bc: jax.Array, Cm_c: jax.Array,
+                        dA_c: jax.Array) -> jax.Array:
+    """Y_diag [B,Cc,Q,H,P] of the chunked SSD's diagonal blocks in jnp, with
+    [B,Cc,H,Q,Q] tensors: the XLA path of ``mamba_forward`` and the oracle
+    of the fused kernel (:func:`repro.kernels.ssd.ssd_intra_chunk`). xc
+    [B,Cc,Q,H,P], dt_c and dA_c [B,Cc,Q,H], Bc and Cm_c [B,Cc,Q,N]."""
+    Lmat = jnp.exp(_segsum(jnp.swapaxes(dA_c, 2, 3)))  # [B,Cc,H,Q,Q]
+    CB = jnp.einsum("bcin,bcjn->bcij", Cm_c, Bc)  # [B,Cc,Q,Q]
+    M = CB[:, :, None] * Lmat  # [B,Cc,H,i,j]
+    return jnp.einsum("bchij,bcjh,bcjhp->bcihp", M, dt_c, xc)
+
+
+def ssd_path(cfg: ModelConfig, S: int) -> str:
+    """The path ``mamba_forward`` runs the intra-chunk SSD on for a sequence
+    of length S: ``'pallas'`` (the fused kernel,
+    :func:`repro.kernels.ssd.ssd_intra_chunk`) where it runs compiled (a
+    TPU, where ``pallas_interpret()`` is False) and the chunk and head
+    shapes suit it (:func:`repro.kernels.ssd.supported`: chunks of whole
+    128-lane tiles, head dim and state of whole 8-row tiles); ``'xla'``
+    (the jnp einsums with ``[B, Cc, H, Q, Q]`` tensors) otherwise."""
+    fits = S % cfg.ssm_chunk == 0 and ssd.supported(
+        cfg.ssm_chunk, cfg.ssm_head_dim, cfg.ssm_state)
+    return "pallas" if fits and not pallas_interpret() else "xla"
+
+
 def mamba_forward(p: PyTree, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    """Full-sequence chunked SSD. x: [B, S, d] with S % chunk == 0."""
+    """Full-sequence chunked SSD. x: [B, S, d] with S % chunk == 0.
+
+    The intra-chunk block runs on :func:`ssd_path` under the device scope
+    ``repro.ssd``."""
     B, S, _ = x.shape
     di, N, H, P, Q = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_chunk
     assert S % Q == 0, f"seq {S} must be divisible by ssm_chunk {Q}"
@@ -94,10 +125,11 @@ def mamba_forward(p: PyTree, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     dAcum = jnp.cumsum(dA_c, axis=2)  # [B,Cc,Q,H]
 
     # --- intra-chunk (diagonal blocks) ---
-    Lmat = jnp.exp(_segsum(jnp.swapaxes(dA_c, 2, 3)))  # [B,Cc,H,Q,Q]
-    CB = jnp.einsum("bcin,bcjn->bcij", Cm_c, Bc)  # [B,Cc,Q,Q]
-    M = CB[:, :, None] * Lmat  # [B,Cc,H,i,j]
-    Y_diag = jnp.einsum("bchij,bcjh,bcjhp->bcihp", M, dt_c, xc)
+    with jax.named_scope(tracing.SSD):
+        if ssd_path(cfg, S) == "pallas":
+            Y_diag = ssd.ssd_intra_chunk(xc, dt_c, Bc, Cm_c, dAcum)
+        else:
+            Y_diag = ssd_intra_chunk_xla(xc, dt_c, Bc, Cm_c, dA_c)
 
     # --- chunk states ---
     decay_states = jnp.exp(dAcum[:, :, -1:, :] - dAcum)  # [B,Cc,Q,H]

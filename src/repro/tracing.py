@@ -13,7 +13,7 @@ shows both:
   by what the host was doing in it. With the profiler off a span costs
   under a microsecond.
 
-Scopes nest: ``ATTENTION`` lies inside ``FWD_BWD`` (and ``EVAL``),
+Scopes nest: ``ATTENTION`` and ``SSD`` lie inside ``FWD_BWD`` (and ``EVAL``),
 ``NEWTON_SCHULZ`` inside ``INNER_OPT``, and
 ``PSEUDOGRAD``, ``REDUCE`` and ``OUTER_UPDATE`` inside ``OUTER_SYNC``.
 """
@@ -23,6 +23,9 @@ FWD_BWD = "repro.fwd_bwd"  # forward, backward and rematerialisation of each inn
 # the attention core of each layer, flash kernel or XLA; kept out of
 # DEVICE_SCOPES until the benchmark's recorded scope trace carries it
 ATTENTION = "repro.attention"
+# Mamba2's intra-chunk SSD block, fused kernel or XLA; kept out of
+# DEVICE_SCOPES for the same reason as ATTENTION
+SSD = "repro.ssd"
 INNER_OPT = "repro.inner_opt"  # the inner optimizer: Muon (momentum, NS, AdamW leaves) or AdamW
 NEWTON_SCHULZ = "repro.newton_schulz"  # Muon's orthogonalisation
 OUTER_SYNC = "repro.outer_sync"  # the whole sync, the three stages below

@@ -15,7 +15,8 @@ Pallas outputs (padding happens inside the mapped region on local shapes,
 so sharding never changes any element's arithmetic) and allclose to the
 oracle. The flash VJP runs under the production composition —
 ``vmap(spmd_axis_name='pod')`` over workers + ``lax.scan`` + ``remat`` —
-and asserts the batch-local grads (dq/dk/dv) bitwise.
+and asserts the batch-local grads (dq/dk/dv) bitwise; the fused SSD's VJP
+runs under the same composition, its five gradients bitwise.
 
 Prints one JSON object on the last stdout line.
 """
@@ -38,8 +39,10 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import gqa_flash_attention, paged_decode_attention
 from repro.kernels.partition import kernel_partitioning
+from repro.kernels.ssd import ssd_intra_chunk
 from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import kernel_specs
+from repro.models.ssm import ssd_intra_chunk_xla
 
 MESH = make_debug_mesh(data=2, model=2, pod=2)
 PARTS = kernel_specs(MESH)
@@ -188,6 +191,40 @@ def main() -> dict:
         "vs_ref": close(rp, single(ref.paged_attention_ref,
                                    qp, kp, vp, tbl, lens)),
     }
+
+    # -- intra-chunk SSD VJP under vmap(spmd)+remat (K=2 workers, B=4
+    #    sequences of 2 chunks: the batch axis splits over 'data') ----------
+    xs = jax.random.normal(k1, (Kw, 4, 2, 128, 2, 64), jnp.float32)
+    bs = jax.random.normal(k2, (Kw, 4, 2, 128, 64), jnp.float32)
+    cs = jax.random.normal(k3, (Kw, 4, 2, 128, 64), jnp.float32)
+    dts = jax.nn.softplus(jax.random.normal(k1, (Kw, 4, 2, 128, 2)) - 3.0)
+    dAs = dts * -jnp.array([1.0, 16.0])
+    w = jax.random.normal(k2, xs.shape[1:])  # a linear loss: dY = w exactly
+
+    def ssd_loss(x, dt, b, c, dA):
+        y = jax.checkpoint(lambda *a: ssd_intra_chunk(
+            *a[:4], jnp.cumsum(a[4], axis=2)))(x, dt, b, c, dA)
+        return jnp.sum(w * y)
+
+    def ssd_grads(loss, spmd):
+        g = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+        return jax.vmap(g, spmd_axis_name=spmd) if spmd else jax.vmap(g)
+
+    ssd_args = (xs, dts, bs, cs, dAs)
+    gs = single(ssd_grads(ssd_loss, None), *ssd_args)
+    with kernel_partitioning(PARTS), jax.set_mesh(MESH):
+        shard = NamedSharding(MESH, P("pod"))
+        args = [jax.device_put(x, shard) for x in ssd_args]
+        gsh = jax.tree.map(np.asarray, jax.jit(ssd_grads(ssd_loss, "pod"))(*args))
+
+    def ssd_loss_ref(x, dt, b, c, dA):
+        return jnp.sum(w * ssd_intra_chunk_xla(x, dt, b, c, dA))
+
+    with jax.default_matmul_precision("highest"):
+        gr = single(ssd_grads(ssd_loss_ref, None), *ssd_args)
+    # the kernel rounds MXU operands to bf16 (tests/test_ssd_kernel.py)
+    rel = max(float(np.max(np.abs(a - b)) / np.max(np.abs(b))) for a, b in zip(gs, gr))
+    out["ssd"] = {"bitwise": bitwise(gsh, gs), "vs_ref": rel < 2.0 ** -6}
     return out
 
 

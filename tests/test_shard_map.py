@@ -42,7 +42,7 @@ def test_harness_world(verdicts):
 
 @pytest.mark.parametrize("kernel", [
     "flash_fwd", "quantize", "dequantize", "ns_orthogonalize",
-    "outer_update", "paged_decode",
+    "outer_update", "paged_decode", "ssd",
 ])
 def test_shard_mapped_bitwise_and_close_to_ref(verdicts, kernel):
     rec = verdicts[kernel]

@@ -8,13 +8,16 @@ widths of ``smollm-135m`` (d_model 576, 9 query / 3 KV heads of 64, d_ff
 1536, 30 layers) and the chip-smoke shapes (K=4 workers, 4 sequences of 2048
 per worker). Nothing runs: a pass means the compiler accepted the kernel and
 its VMEM footprint, not that it is fast or right (the interpret-mode tests
-and the chip run cover results).
+and the chip run cover results). The fused SSD kernel compiles at the widths
+of ``mamba2-370m`` (8 sequences of 2048 in chunks of 256, 32 heads of 64,
+state 128, vmapped over K=1) and of ``zamba2-2.7b`` (80 heads, state 64).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the suite runs under
 several xdist workers that each import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +33,8 @@ from repro.kernels.flash_attention import (
 from repro.kernels.matmul import matmul_epilogue
 from repro.kernels.outer_update import fused_nesterov_update
 from repro.kernels.quantize import rowwise_dequantize, rowwise_quantize
+from repro.kernels import ssd
+from repro.kernels.ssd import ssd_intra_chunk
 
 # smollm-135m widths and the chip-smoke batch
 K, B, S = 4, 4, 2048
@@ -153,3 +158,67 @@ def test_outer_update_compiles(one_chip):
 
     _compile(outer, one_chip, ((n,), jnp.float32), ((n,), jnp.float32),
              ((n,), jnp.float32))
+
+
+# mamba2-370m's SSD at the benchmark cell's batch: 8 x 2048 in chunks of 256
+SSD_B, SSD_CC, SSD_Q, SSD_P = 8, 8, 256, 64
+
+
+@pytest.mark.parametrize("H,N", [(32, 128), (80, 64)], ids=["mamba2", "zamba2"])
+def test_ssd_fwd_and_bwd_compile(one_chip, H, N):
+    """The fused SSD forward and backward kernels, vmapped over K=1."""
+    def fwd_bwd(*args):
+        y, vjp = jax.vjp(lambda *z: ssd_intra_chunk(*z, interpret=False), *args)
+        return y, vjp(jnp.sin(y))
+
+    lead = (1, SSD_B, SSD_CC, SSD_Q)
+    compiled = _compile(jax.vmap(fwd_bwd), one_chip, ((*lead, H, SSD_P), jnp.float32),
+                        ((*lead, H), jnp.float32), ((*lead, N), jnp.float32),
+                        ((*lead, N), jnp.float32), ((*lead, H), jnp.float32))
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+def test_ssd_layer_holds_no_chunk_square_buffer(one_chip, monkeypatch):
+    """One mamba2-370m layer's ``grad(checkpoint(mamba_forward))`` on the
+    kernel path: no f32 ``[..., 256, 256]`` buffer outside the kernel
+    (the XLA path holds ``f32[8,8,32,256,256]``)."""
+    from repro.configs import get_config
+    from repro.models import ssm
+
+    # steer the backend checks, which see the CPU here, to the TPU's path
+    monkeypatch.setattr(ssm, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(ssd, "pallas_interpret", lambda: False)
+    cfg = get_config("mamba2-370m")
+    S = SSD_CC * SSD_Q
+    assert ssm.ssd_path(cfg, S) == "pallas"
+    params = jax.eval_shape(lambda: ssm.init_mamba(jax.random.PRNGKey(0), cfg))
+
+    def loss(p, x):
+        y = jax.checkpoint(lambda p, x: ssm.mamba_forward(p, cfg, x))(p, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    shapes = [((1, *a.shape), a.dtype) for a in jax.tree.leaves(params)]
+    treedef = jax.tree.structure(params)
+
+    def grad(x, *leaves):
+        return jax.vmap(jax.grad(loss))(jax.tree.unflatten(treedef, leaves), x)
+
+    compiled = _compile(grad, one_chip, ((1, SSD_B, S, cfg.d_model), jnp.bfloat16),
+                        *shapes)
+    text = compiled.as_text()
+    square = re.findall(r"f32\[[0-9,]*256,256\]", text)
+    assert not square, sorted(set(square))
+    kernels = re.findall(r'tpu_custom_call.*op_name="([^"]*)"', text)
+    assert kernels and all("repro.ssd" in n for n in kernels), kernels
+
+
+def test_ssd_layer_described_cost(one_chip):
+    """``benchmarks/ssd_layer.py --described``, the static reading of one
+    mamba2-370m layer on both SSD paths: the f32 ``[..., 256, 256]``
+    tensors and most of the ``exp`` work leave the XLA program."""
+    from benchmarks import ssd_layer
+
+    out = ssd_layer.described()
+    assert "f32[8,8,32,256,256]" in out["xla"]["f32_chunk_square_shapes"]
+    assert out["pallas"]["f32_chunk_square_shapes"] == []
+    assert out["pallas"]["transcendentals"] < out["xla"]["transcendentals"] / 3
